@@ -32,8 +32,29 @@ from .nvfp4_quant import DEFAULT_TILE_L, DEFAULT_TILE_M, _qdq_tile
 _EPS = 1e-30
 
 
+# Column tile of the reduction passes (mean here, amax in ``fused``): rows
+# of up to 256 x 2048 fp32 = 2 MiB per block, well inside v5e's 16 MiB of
+# scoped VMEM; a vocab-wide row (151,936) cannot be one block.
+REDUCE_MAX_COLS = 2048
+
+
+def reduce_tile_m(m: int) -> int:
+    """Column tile of a reduction pass over (l, m): the whole row while it
+    fits, else a 128-multiple divisor of ``m``, else ``REDUCE_MAX_COLS``
+    with padded columns."""
+    if m <= REDUCE_MAX_COLS:
+        return m
+    for cand in (REDUCE_MAX_COLS, 3 * REDUCE_MAX_COLS // 4,
+                 REDUCE_MAX_COLS // 2):
+        if m % cand == 0:
+            return cand
+    return REDUCE_MAX_COLS
+
+
 def _mean_kernel(x_ref, o_ref, *, n_rows: int):
-    i = pl.program_id(0)
+    """Grid (column tiles, row tiles), rows innermost: the (1, tile_m)
+    output block accumulates its columns' sums in row-tile order."""
+    i = pl.program_id(1)
     part = jnp.sum(x_ref[...].astype(jnp.float32), axis=0, keepdims=True)
 
     @pl.when(i == 0)
@@ -52,18 +73,21 @@ def column_mean_2d(
     """mu = (1/l) 1^T X for X (l, m); returns (1, m) fp32."""
     l, m = x.shape
     tile_l = min(tile_l, max(8, l))
+    tile_m = reduce_tile_m(m)
     pad_l = (-l) % tile_l
-    xp = jnp.pad(x, ((0, pad_l), (0, 0)))  # zero rows don't perturb the sum
-    grid = (xp.shape[0] // tile_l,)
+    pad_m = (-m) % tile_m
+    # zero rows don't perturb the sum; padded columns are sliced off
+    xp = jnp.pad(x, ((0, pad_l), (0, pad_m)))
+    grid = (xp.shape[1] // tile_m, xp.shape[0] // tile_l)
     out = pl.pallas_call(
         functools.partial(_mean_kernel, n_rows=l),
-        out_shape=jax.ShapeDtypeStruct((1, m), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, xp.shape[1]), jnp.float32),
         grid=grid,
-        in_specs=[pl.BlockSpec((tile_l, m), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, m), lambda i: (0, 0)),
+        in_specs=[pl.BlockSpec((tile_l, tile_m), lambda j, i: (i, j))],
+        out_specs=pl.BlockSpec((1, tile_m), lambda j, i: (0, j)),
         interpret=interpret,
     )(xp)
-    return out
+    return out[:, :m]
 
 
 def _split_qdq_kernel(x_ref, mu_ref, st_ref, o_ref):

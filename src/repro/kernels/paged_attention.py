@@ -35,15 +35,18 @@ so empty pages and all-masked rows stay NaN-free.
 
 Two interchangeable page-partial backends implement the same algorithm:
 
-* ``_page_partials_pallas`` — the Pallas kernel, grid ``(b, n_kv, n_pages)``
-  with pages innermost (sequential on TPU, so the output blocks double as
-  the online-softmax accumulators); E2M1 decode is gather-free arithmetic
-  on the code bits (``_decode_e2m1_arith``). Runs compiled on TPU,
+* ``_page_partials_pallas`` — the Pallas kernel, grid ``(b, n_kv, page
+  groups)`` with groups innermost (sequential on TPU, so the output blocks
+  double as the online-softmax accumulators); E2M1 and E4M3 decode are
+  gather-free arithmetic on the code bits, and the nibble interleave and
+  per-block scale expansion are exact one-hot MXU matmuls (Mosaic cannot
+  interleave or split lanes, and v5e has no fp8). Runs compiled on TPU,
   interpreted elsewhere (the ``kernels/fused.py`` convention).
 * ``_page_partials_xla`` — a ``lax.scan`` twin over pages built on the
-  shared ``core/nvfp4`` codec helpers. Identical math, still no dense KV
-  tensor; it is what the serving engine uses off-TPU, where interpreted
-  Pallas in the decode hot loop would be pure overhead.
+  shared ``core/nvfp4`` codec helpers. Identical math and the same
+  online-softmax schedule (``_pages_per_step`` pages per step), still no
+  dense KV tensor; it is what the serving engine uses off-TPU, where
+  interpreted Pallas in the decode hot loop would be pure overhead.
 
 ``backend="auto"`` picks Pallas on TPU and the XLA twin elsewhere.
 
@@ -63,7 +66,9 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import BLOCK_SIZE, TENSOR_SCALE_DENOM
 from repro.core.nvfp4 import decode_e2m1_codes, unpack_nibbles
@@ -72,6 +77,12 @@ from repro.core.nvfp4 import decode_e2m1_codes, unpack_nibbles
 # on fully-masked rows instead of the NaN that -inf would produce.
 NEG_INF = -1e30
 _EPS = 1e-30
+# fp32 operands stay fp32 on the MXU (the TPU's default f32 matmul rounds
+# them to bf16); the one-hot codec matmuls are bf16 by construction. Every
+# f32 contraction here - kernel, XLA twin, tail/span blocks - uses it, so the
+# read's numerics do not depend on the caller's default matmul precision.
+_F32 = jax.lax.Precision.HIGHEST
+_einsum = functools.partial(jnp.einsum, precision=_F32)
 
 Partial = Tuple[jax.Array, jax.Array, jax.Array]   # (m, l, acc)
 
@@ -87,96 +98,166 @@ def _interpret_default() -> bool:
 def _decode_e2m1_arith(codes: jax.Array) -> jax.Array:
     """4-bit sign|magnitude E2M1 codes -> signed float32 grid values.
 
-    Pure bit arithmetic (no table gather — Pallas/TPU friendly):
-    ``m = code & 7`` splits into exponent ``e = m >> 1`` and mantissa bit
-    ``man = m & 1``; subnormal row ``e == 0`` decodes to ``0.5 * man``,
-    normal rows to ``(1 + man/2) * 2^(e-1)``. Bit-exact to
+    Pure arithmetic on small integers (no table gather, no exp2 — Pallas/
+    TPU friendly): magnitude ``m = code & 7`` maps to the grid
+    {0, .5, 1, 1.5, 2, 3, 4, 6} as ``m/2`` (m < 4), ``m - 2`` (m < 6) or
+    ``2m - 8``, every step exact in fp32. Bit-exact to
     ``core.nvfp4.decode_e2m1_codes`` over all 256 byte values (asserted in
     tests/test_paged_attention.py).
     """
-    m = codes & 7
-    e = m >> 1
-    man = (m & 1).astype(jnp.float32)
-    mag = jnp.where(e == 0, 0.5 * man,
-                    (1.0 + 0.5 * man) * jnp.exp2((e - 1).astype(jnp.float32)))
+    m = (codes & 7).astype(jnp.float32)
+    mag = jnp.where(m < 4.0, 0.5 * m, jnp.where(m < 6.0, m - 2.0, 2.0 * m - 8.0))
     return jnp.where(codes >= 8, -mag, mag)
 
 
-def _unpack_tile(codes_u8: jax.Array) -> jax.Array:
-    """(..., hd//2) uint8 -> (..., hd) int32 codes, low nibble first
-    (the ``core.nvfp4.pack_nibbles`` order)."""
-    lo = (codes_u8 & 0x0F).astype(jnp.int32)
-    hi = (codes_u8 >> 4).astype(jnp.int32)
-    return jnp.stack([lo, hi], axis=-1).reshape(
-        codes_u8.shape[:-1] + (2 * codes_u8.shape[-1],))
+def _decode_e4m3_bits(b: jax.Array) -> jax.Array:
+    """float8_e4m3fn bytes (any int dtype) -> float32 values, by field
+    arithmetic: v5e has no fp8 unit, so Mosaic cannot convert fp8.
+
+    Exponent field ``e = (b >> 3) & 15``, mantissa ``m = b & 7``: normals
+    are ``(8 + m) * 2^(e - 10)``, subnormals ``m * 2^-9`` — built as an
+    exact integer times 2^-9. Bit-exact to the fp8 cast for every non-NaN
+    byte (E4M3 block scales are clipped to 448, so never NaN).
+    """
+    b = b.astype(jnp.int32)
+    e = (b >> 3) & 15
+    m = b & 7
+    mag = jnp.where(e == 0, m, (8 + m) << jnp.maximum(e - 1, 0))
+    v = mag.astype(jnp.float32) * (1.0 / 512.0)
+    return jnp.where(b >= 128, -v, v)
 
 
-def _dequant_tile(codes_u8: jax.Array, scales_f8: jax.Array, s_t: jax.Array,
-                  *, block_size: int) -> jax.Array:
-    """One page tile (P, hd//2) u8 + (P, hd//block) f8 + scalar s_t ->
-    float32 residual (P, hd). In-kernel version (arithmetic decode)."""
-    vals = _decode_e2m1_arith(_unpack_tile(codes_u8))
-    hd = vals.shape[-1]
-    scale = scales_f8.astype(jnp.float32) * s_t
-    rb = vals.reshape(vals.shape[:-1] + (hd // block_size, block_size))
-    return (rb * scale[..., None]).reshape(vals.shape)
+def _interleave_matrices(half: int) -> Tuple[jax.Array, jax.Array]:
+    """One-hot (half, 2*half) bf16 matrices scattering low/high nibbles to
+    the even/odd columns of the ``pack_nibbles`` order. Mosaic cannot
+    interleave lanes, so the kernels do it on the MXU: every output is one
+    small integer times 1 plus zeros, exact."""
+    lo = np.zeros((half, 2 * half), np.float32)
+    k = np.arange(half)
+    lo[k, 2 * k] = 1.0
+    hi = np.zeros_like(lo)
+    hi[k, 2 * k + 1] = 1.0
+    return jnp.asarray(lo, jnp.bfloat16), jnp.asarray(hi, jnp.bfloat16)
+
+
+def _expand_matrix(n_blocks: int, block_size: int) -> jax.Array:
+    """One-hot (n_blocks, n_blocks*block_size) bf16 matrix repeating each
+    block scale over its block (exact: E4M3 values are bf16 values)."""
+    e = np.repeat(np.eye(n_blocks, dtype=np.float32), block_size, axis=1)
+    return jnp.asarray(e, jnp.bfloat16)
+
+
+def _unpack_codes_mxu(codes_u8, lo_m, hi_m) -> jax.Array:
+    """(R, W) packed bytes -> (R, 2W) int32 codes in ``pack_nibbles``
+    order, interleaved on the MXU (``_interleave_matrices``)."""
+    c = codes_u8.astype(jnp.int32)
+    lo = (c & 15).astype(jnp.float32).astype(jnp.bfloat16)
+    hi = (c >> 4).astype(jnp.float32).astype(jnp.bfloat16)
+    codes = (jnp.dot(lo, lo_m, preferred_element_type=jnp.float32)
+             + jnp.dot(hi, hi_m, preferred_element_type=jnp.float32))
+    return codes.astype(jnp.int32)
+
+
+def _block_scales_mxu(scale_bytes, expand) -> jax.Array:
+    """(R, nb) E4M3 scale bytes -> (R, nb*block) fp32 per-element scales."""
+    s_b = _decode_e4m3_bits(scale_bytes).astype(jnp.bfloat16)
+    return jnp.dot(s_b, expand, preferred_element_type=jnp.float32)
+
+
+def _dequant_tile_mxu(codes_u8, scale_bytes, s_t, lo_m, hi_m, expand):
+    """One page tile (P, hd//2) u8 + (P, hd//block) E4M3 bytes + scalar
+    s_t -> float32 residual (P, hd), in the form Mosaic compiles: the same
+    values, in the same op order, as ``_page_partials_xla``'s dequant
+    (``vals * (s_b * s_t)``)."""
+    vals = _decode_e2m1_arith(_unpack_codes_mxu(codes_u8, lo_m, hi_m))
+    return vals * (_block_scales_mxu(scale_bytes, expand) * s_t)
 
 
 # --------------------------------------------------------------------------
 # Pallas page-partials kernel
 # --------------------------------------------------------------------------
 
-def _flash_kernel(pidx_ref, q_ref, ck_ref, sk_ref, cv_ref, sv_ref, pa_ref,
-                  *rest, sm_scale: float, block_size: int, centered: bool):
-    """Grid (b, n_kv, n_pages), pages innermost. The output blocks (indexed
-    independently of the page axis) are the online-softmax accumulators:
-    init at j == 0, accumulate while j < pidx, final values stand when the
-    page loop ends. Committed pages are always full, so no per-token mask
-    is needed inside a valid page."""
+def _pages_per_step(n_pages: int, page: int) -> int:
+    """Pages per online-softmax step, shared by both backends: ~128 tokens
+    per step. The running max moves once per step, so the kernel and the
+    twin exponentiate the same ``logits - m`` only if they step alike — a
+    TPU's f32 ``exp`` is off by several ULPs, and a different schedule
+    rounds differently (several 1e-6 at real widths)."""
+    return max(1, min(n_pages, 128 // page))
+
+
+def _flash_kernel(pidx_ref, st_ref, q_ref, ck_ref, sk_ref, cv_ref, sv_ref,
+                  lo_ref, hi_ref, ex_ref, *rest, n_pages: int, group: int,
+                  sm_scale: float, centered: bool):
+    """Grid (b, n_kv, page groups), groups innermost; each step reads
+    ``group`` pages (``_pages_per_step``), as the XLA twin does. The output
+    blocks (indexed independently of the group axis) are the online-softmax
+    accumulators: init at t == 0, accumulate while the group holds a
+    committed page, final values stand when the loop ends. Pages past
+    ``pidx`` in the last group are masked out of the max and of ``p``;
+    committed pages are always full, so no per-token mask is needed.
+    ``pidx`` and the per-page tensor scales ``st`` (flat (b, n_pages, 2))
+    are SMEM scalars."""
     if centered:
         mk_ref, mv_ref, acc_ref, m_ref, l_ref = rest
     else:
         acc_ref, m_ref, l_ref = rest
-    j = pl.program_id(2)
+    bi, t = pl.program_id(0), pl.program_id(2)
+    n_valid = pidx_ref[bi]
 
-    @pl.when(j == 0)
+    @pl.when(t == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(j < pidx_ref[0, 0])
+    @pl.when(t * group < n_valid)
     def _accumulate():
-        q = q_ref[0, 0]                                   # (sg, hd) f32
-        pa = pa_ref[0, 0]                                 # (2,) f32
-        s_tk = jnp.maximum(pa[0] / TENSOR_SCALE_DENOM, _EPS)
-        s_tv = jnp.maximum(pa[1] / TENSOR_SCALE_DENOM, _EPS)
-        res_k = _dequant_tile(ck_ref[0, 0, :, 0, :], sk_ref[0, 0, :, 0, :],
-                              s_tk, block_size=block_size)    # (P, hd)
-        logits = jax.lax.dot_general(                         # (sg, P)
-            q, res_k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if centered:
-            mu_k = mk_ref[0, 0, 0].astype(jnp.float32)        # (hd,)
-            # the whole page's mean contribution: one scalar per head row
-            logits = logits + (q @ mu_k)[:, None]
-        logits = logits * sm_scale
+        mats = (lo_ref[...], hi_ref[...], ex_ref[...])
+        q = q_ref[0, 0]                                       # (sg, hd) f32
+        pages = []
+        for i in range(group):
+            j = t * group + i
+            st = jnp.minimum(j, n_pages - 1) * 2              # padded: clip
+            res_k = _dequant_tile_mxu(ck_ref[0, i, 0], sk_ref[0, i, 0],
+                                      st_ref[(bi * n_pages) * 2 + st],
+                                      *mats)                  # (P, hd)
+            logits = jax.lax.dot_general(                     # (sg, P)
+                q, res_k, (((1,), (1,)), ((), ())),
+                precision=_F32, preferred_element_type=jnp.float32)
+            if centered:
+                mu_k = mk_ref[0, i, 0].astype(jnp.float32)    # (1, hd)
+                # the whole page's mean contribution: one scalar per row
+                logits = logits + jnp.sum(q * mu_k, axis=-1, keepdims=True)
+            pages.append((logits * sm_scale, j < n_valid, st))
 
         m_prev = m_ref[0, 0]                                  # (sg, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        m_new = m_prev
+        for logits, ok, _ in pages:
+            m_new = jnp.maximum(m_new, jnp.where(
+                ok, jnp.max(logits, axis=-1, keepdims=True), NEG_INF))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(logits - m_new)                           # (sg, P)
-        psum = jnp.sum(p, axis=-1, keepdims=True)
-
-        res_v = _dequant_tile(cv_ref[0, 0, :, 0, :], sv_ref[0, 0, :, 0, :],
-                              s_tv, block_size=block_size)
-        acc = acc_ref[0, 0] * alpha + jax.lax.dot_general(
-            p, res_v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        upd = psum = mean_upd = None
+        for i, (logits, ok, st) in enumerate(pages):
+            p = jnp.where(ok, jnp.exp(logits - m_new), 0.0)   # (sg, P)
+            ps = jnp.sum(p, axis=-1, keepdims=True)
+            res_v = _dequant_tile_mxu(cv_ref[0, i, 0], sv_ref[0, i, 0],
+                                      st_ref[(bi * n_pages) * 2 + st + 1],
+                                      *mats)
+            pv = jax.lax.dot_general(
+                p, res_v, (((1,), (0,)), ((), ())),
+                precision=_F32, preferred_element_type=jnp.float32)
+            upd = pv if upd is None else upd + pv
+            psum = ps if psum is None else psum + ps
+            if centered:
+                # mu_v * sum(p); a masked page adds 0 whatever its mean
+                mean_v = jnp.where(
+                    ok, ps * mv_ref[0, i, 0].astype(jnp.float32), 0.0)
+                mean_upd = (mean_v if mean_upd is None
+                            else mean_upd + mean_v)
         if centered:
-            mu_v = mv_ref[0, 0, 0].astype(jnp.float32)
-            acc = acc + psum * mu_v[None, :]                  # mu_v * sum(p)
-        acc_ref[0, 0] = acc
+            upd = upd + mean_upd
+        acc_ref[0, 0] = acc_ref[0, 0] * alpha + upd
         m_ref[0, 0] = m_new
         l_ref[0, 0] = l_ref[0, 0] * alpha + psum
 
@@ -186,42 +267,60 @@ def _page_partials_pallas(q, ck, sk, cv, sv, pamax, mk, mv, pidx, *,
                           interpret: Optional[bool] = None) -> Partial:
     """Pallas page partials. q (b, n_kv, sg, hd) f32; codes/scales per
     stream (b, np, P, n_kv, hd//2|nb); pamax (b, np, 2) f32; means
-    (b, np, n_kv, hd) or None; pidx (b,) int32."""
+    (b, np, n_kv, hd) or None; pidx (b,) int32.
+
+    Mosaic blocks the last two dims to full extent (or (8, 128) multiples),
+    so each stream's pages are laid out head-major, (b, np, n_kv, P, ·),
+    in the copy that already splits K from V, and padded to whole page
+    groups; scale bytes enter as uint8 (no fp8 on v5e); ``pidx`` and the
+    tensor scales ride in SMEM.
+    """
     b, nkv, sg, hd = q.shape
     np_, p = ck.shape[1], ck.shape[2]
     nb = sk.shape[-1]
     centered = mk is not None
     interp = _interpret_default() if interpret is None else interpret
+    group = _pages_per_step(np_, p)
+    n_groups = -(-np_ // group)
+    pad = n_groups * group - np_
 
-    kernel = functools.partial(_flash_kernel, sm_scale=float(sm_scale),
-                               block_size=block_size, centered=centered)
-    page_spec = lambda blk: pl.BlockSpec(blk, lambda bi, ki, j: (bi, j, 0, ki, 0))
-    head_spec = lambda blk: pl.BlockSpec(blk, lambda bi, ki, j: (bi, ki, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1), lambda bi, ki, j: (bi, 0)),           # pidx
-        head_spec((1, 1, sg, hd)),                                 # q
-        page_spec((1, 1, p, 1, hd // 2)),                          # ck
-        page_spec((1, 1, p, 1, nb)),                               # sk
-        page_spec((1, 1, p, 1, hd // 2)),                          # cv
-        page_spec((1, 1, p, 1, nb)),                               # sv
-        pl.BlockSpec((1, 1, 2), lambda bi, ki, j: (bi, j, 0)),     # pamax
-    ]
-    args = [pidx.astype(jnp.int32).reshape(b, 1), q, ck, sk, cv, sv, pamax]
+    def paged(a):                                 # (b, np+pad, n, P, ·)
+        a = jnp.moveaxis(a, 3, 2)
+        return jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+
+    as_bytes = lambda a: jax.lax.bitcast_convert_type(a, jnp.uint8)
+    s_t = jnp.maximum(pamax.astype(jnp.float32) / TENSOR_SCALE_DENOM, _EPS)
+    lo_m, hi_m = _interleave_matrices(hd // 2)
+    expand = _expand_matrix(nb, block_size)
+
+    kernel = functools.partial(_flash_kernel, n_pages=np_, group=group,
+                               sm_scale=float(sm_scale), centered=centered)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    page_spec = lambda w: pl.BlockSpec((1, group, 1, p, w),
+                                       lambda bi, ki, t: (bi, t, ki, 0, 0))
+    head_spec = lambda w: pl.BlockSpec((1, 1, sg, w),
+                                       lambda bi, ki, t: (bi, ki, 0, 0))
+    const = lambda a: pl.BlockSpec(a.shape, lambda bi, ki, t: (0, 0))
+    in_specs = [smem, smem, head_spec(hd),
+                page_spec(hd // 2), page_spec(nb),
+                page_spec(hd // 2), page_spec(nb),
+                const(lo_m), const(hi_m), const(expand)]
+    args = [pidx.astype(jnp.int32), s_t.reshape(-1), q,
+            paged(ck), as_bytes(paged(sk)),
+            paged(cv), as_bytes(paged(sv)), lo_m, hi_m, expand]
     if centered:
-        mean_spec = pl.BlockSpec((1, 1, 1, hd),
-                                 lambda bi, ki, j: (bi, j, ki, 0))
+        mean_spec = pl.BlockSpec((1, group, 1, 1, hd),
+                                 lambda bi, ki, t: (bi, t, ki, 0, 0))
         in_specs += [mean_spec, mean_spec]
-        args += [mk, mv]
+        args += [paged(mk[:, :, None]), paged(mv[:, :, None])]
     out_shape = [
         jax.ShapeDtypeStruct((b, nkv, sg, hd), jnp.float32),       # acc
         jax.ShapeDtypeStruct((b, nkv, sg, 1), jnp.float32),        # m
         jax.ShapeDtypeStruct((b, nkv, sg, 1), jnp.float32),        # l
     ]
-    out_specs = [head_spec((1, 1, sg, hd)),
-                 head_spec((1, 1, sg, 1)),
-                 head_spec((1, 1, sg, 1))]
+    out_specs = [head_spec(hd), head_spec(1), head_spec(1)]
     acc, m, l = pl.pallas_call(
-        kernel, grid=(b, nkv, np_), in_specs=in_specs,
+        kernel, grid=(b, nkv, n_groups), in_specs=in_specs,
         out_specs=out_specs, out_shape=out_shape,
         interpret=interp)(*args)
     return m, l, acc
@@ -246,7 +345,7 @@ def _page_partials_xla(q, ck, sk, cv, sv, pamax, mk, mv, pidx, *,
     b, nkv, sg, hd = q.shape
     np_, p = ck.shape[1], ck.shape[2]
     centered = mk is not None
-    G = max(1, min(np_, 128 // p))                 # pages per loop iteration
+    G = _pages_per_step(np_, p)                    # pages per loop iteration
 
     def dequant(codes, scales, s_t):
         """codes (b,G,P,n,hd//2), scales (b,G,P,n,nb), s_t (b,G)."""
@@ -263,11 +362,11 @@ def _page_partials_xla(q, ck, sk, cv, sv, pamax, mk, mv, pidx, *,
         s_tv = jnp.maximum(pa[..., 1] / TENSOR_SCALE_DENOM, _EPS)
         res_k = dequant(jnp.take(ck, js, axis=1, mode="clip"),
                         jnp.take(sk, js, axis=1, mode="clip"), s_tk)
-        logits = jnp.einsum("bnsh,bgpnh->bnsgp", q, res_k)  # (b,n,sg,G,P)
+        logits = _einsum("bnsh,bgpnh->bnsgp", q, res_k)  # (b,n,sg,G,P)
         if centered:
             mkc = jnp.take(mk, js, axis=1,
                            mode="clip").astype(jnp.float32)  # (b,G,n,hd)
-            qmu = jnp.einsum("bnsh,bgnh->bnsg", q, mkc)
+            qmu = _einsum("bnsh,bgnh->bnsg", q, mkc)
             logits = logits + qmu[..., None]
         logits = (logits * sm_scale).reshape(b, nkv, sg, G * p)
 
@@ -281,13 +380,13 @@ def _page_partials_xla(q, ck, sk, cv, sv, pamax, mk, mv, pidx, *,
         psum = jnp.sum(pmat, axis=-1, keepdims=True)
         res_v = dequant(jnp.take(cv, js, axis=1, mode="clip"),
                         jnp.take(sv, js, axis=1, mode="clip"), s_tv)
-        upd = jnp.einsum("bnsk,bknh->bnsh", pmat,
-                         res_v.reshape(b, G * p, nkv, hd))
+        upd = _einsum("bnsk,bknh->bnsh", pmat,
+                      res_v.reshape(b, G * p, nkv, hd))
         if centered:
             mvc = jnp.take(mv, js, axis=1,
                            mode="clip").astype(jnp.float32)  # (b,G,n,hd)
             pg = pmat.reshape(b, nkv, sg, G, p).sum(-1)      # (b,n,sg,G)
-            upd = upd + jnp.einsum("bnsg,bgnh->bnsh", pg, mvc)
+            upd = upd + _einsum("bnsg,bgnh->bnsh", pg, mvc)
         return (m_new, l * alpha + psum, acc * alpha + upd)
 
     init = (jnp.full((b, nkv, sg, 1), NEG_INF, jnp.float32),
@@ -305,12 +404,12 @@ def _block_partial(q, kb, vb, valid, *, sm_scale: float) -> Partial:
     """Softmax partial over one exact K/V block. q (b, n, sg, hd) f32;
     kb/vb (b, n, T, hd) f32; valid (b, sg, T) or (b, 1, T) bool. No mean
     term — the tail and the speculative span are stored exact."""
-    logits = jnp.einsum("bnsh,bnth->bnst", q, kb) * sm_scale
+    logits = _einsum("bnsh,bnth->bnst", q, kb) * sm_scale
     logits = jnp.where(valid[:, None], logits, NEG_INF)
     m = jnp.max(logits, axis=-1, keepdims=True)
     p = jnp.where(valid[:, None], jnp.exp(logits - m), 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    acc = jnp.einsum("bnst,bnth->bnsh", p, vb)
+    acc = _einsum("bnst,bnth->bnsh", p, vb)
     return m, l, acc
 
 
@@ -461,13 +560,13 @@ def paged_attend_mla(q_abs, q_rope, codes, scales, pamax, mean, kr, tail,
         res = dequant(jnp.take(codes, js, axis=1, mode="clip"),
                       jnp.take(scales, js, axis=1, mode="clip"),
                       s_t)                                    # (b,G,P,rkv)
-        logits = (jnp.einsum("bhr,bgpr->bhgp", qa, res)
-                  + jnp.einsum("bhd,bgpd->bhgp", qr,
-                               jnp.take(krp, js, axis=1, mode="clip")))
+        logits = (_einsum("bhr,bgpr->bhgp", qa, res)
+                  + _einsum("bhd,bgpd->bhgp", qr,
+                            jnp.take(krp, js, axis=1, mode="clip")))
         if centered:
             mc = jnp.take(mean, js, axis=1,
                           mode="clip").astype(jnp.float32)    # (b,G,rkv)
-            qmu = jnp.einsum("bhr,bgr->bhg", qa, mc)
+            qmu = _einsum("bhr,bgr->bhg", qa, mc)
             logits = logits + qmu[..., None]
         logits = (logits * sm_scale).reshape(b, nh, G * p)
 
@@ -479,11 +578,11 @@ def paged_attend_mla(q_abs, q_rope, codes, scales, pamax, mean, kr, tail,
         alpha = jnp.exp(m - m_new)
         pmat = jnp.where(vmask, jnp.exp(logits - m_new), 0.0)
         psum = jnp.sum(pmat, axis=-1, keepdims=True)
-        upd = jnp.einsum("bhk,bkr->bhr", pmat,
-                         res.reshape(b, G * p, rkv))
+        upd = _einsum("bhk,bkr->bhr", pmat,
+                      res.reshape(b, G * p, rkv))
         if centered:
             pg = pmat.reshape(b, nh, G, p).sum(-1)            # (b,nh,G)
-            upd = upd + jnp.einsum("bhg,bgr->bhr", pg, mc)
+            upd = upd + _einsum("bhg,bgr->bhr", pg, mc)
         return (m_new, l * alpha + psum, acc * alpha + upd)
 
     init = (jnp.full((b, nh, 1), NEG_INF, jnp.float32),
@@ -500,12 +599,12 @@ def paged_attend_mla(q_abs, q_rope, codes, scales, pamax, mean, kr, tail,
     tc = tail.astype(jnp.float32)                             # (b, P, rkv)
     kr_tail = jnp.take_along_axis(
         krp, pidx[:, None, None, None], axis=1)[:, 0]         # (b, P, dr)
-    logits_t = (jnp.einsum("bhr,bpr->bhp", qa, tc)
-                + jnp.einsum("bhd,bpd->bhp", qr, kr_tail)) * sm_scale
+    logits_t = (_einsum("bhr,bpr->bhp", qa, tc)
+                + _einsum("bhd,bpd->bhp", qr, kr_tail)) * sm_scale
     logits_t = jnp.where(tval[:, None], logits_t, NEG_INF)
     mt = jnp.max(logits_t, axis=-1, keepdims=True)
     pt = jnp.where(tval[:, None], jnp.exp(logits_t - mt), 0.0)
     lt = jnp.sum(pt, axis=-1, keepdims=True)
-    at = jnp.einsum("bhp,bpr->bhr", pt, tc)
+    at = _einsum("bhp,bpr->bhr", pt, tc)
 
     return _finalize(combine_partials([(m, l, acc), (mt, lt, at)]))

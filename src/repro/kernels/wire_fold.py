@@ -37,9 +37,11 @@ why ``--wire {packed,decoded}`` are distinct, each internally bitwise).
 
 Backends (the PR 8 playbook):
 
-* ``_fold_packets_pallas`` — sequential-grid kernel, grid ``(cols, S)``
-  with shards innermost so the output block is the fold accumulator;
-  compiled on TPU, interpreted elsewhere.
+* ``_fold_packets_pallas`` — grid (row tiles, S) with the shard axis
+  innermost and sequential; the output block is the fold accumulator.
+  Nibble interleave and scale expansion are one-hot MXU matmuls (Mosaic
+  cannot split or interleave lanes). Compiled on TPU, interpreted
+  elsewhere.
 * ``_fold_packets_xla`` — a ``lax.scan`` twin whose chunk is one shard's
   packed payload: decode-in-body, never materializing the (S, B) fp32
   stack. The shipping CPU path (interpreted Pallas in the reduce hot loop
@@ -58,14 +60,23 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
 from repro.core.formats import BLOCK_SIZE, TENSOR_SCALE_DENOM
-from repro.kernels.paged_attention import _decode_e2m1_arith, _unpack_tile
+from repro.kernels.paged_attention import (_block_scales_mxu,
+                                           _decode_e2m1_arith,
+                                           _expand_matrix,
+                                           _interleave_matrices,
+                                           _unpack_codes_mxu)
 
 _EPS = 1e-30
 
-# Column-tile candidates for the Pallas fold; every packet payload is padded
-# to a multiple of 2*BLOCK_SIZE elements by the encoder, so 32 always tiles.
-_FOLD_TILE_COLS = (65536, 16384, 4096, 1024, 256, 32)
+# The Pallas fold views a payload as rows of _FOLD_ROW elements (128 code
+# bytes each) and folds _FOLD_TILE_ROWS rows of one shard per grid step (a
+# multiple of 32, the uint8 sublane tile; or all rows when there are few).
+_FOLD_ROW = 256
+_FOLD_TILE_ROWS = (512, 256, 128, 64, 32)
 
 
 # --------------------------------------------------------------------------
@@ -99,20 +110,41 @@ def shard_tensor_scales(amax: jax.Array) -> jax.Array:
     return jnp.maximum(amax.astype(jnp.float32) / TENSOR_SCALE_DENOM, _EPS)
 
 
+def _row_matrices():
+    """The one-hot nibble-interleave and scale-expansion matrices of one
+    _FOLD_ROW-element row (see ``paged_attention._interleave_matrices``)."""
+    return (*_interleave_matrices(_FOLD_ROW // 2),
+            _expand_matrix(_FOLD_ROW // BLOCK_SIZE, BLOCK_SIZE))
+
+
+def _decode_rows(codes, scales, s_t, mats) -> jax.Array:
+    """(R, 128) code bytes + (R, 16) E4M3 scale bytes + scalar s_t ->
+    (R, 256) fp32 values: ``vals * (s_b * s_t)``, the QDQ's own
+    ``sign * q * scale`` (exact fp32 products of exact grid values)."""
+    lo_m, hi_m, expand = mats
+    vals = _decode_e2m1_arith(_unpack_codes_mxu(codes, lo_m, hi_m))
+    return vals * (_block_scales_mxu(scales, expand) * s_t)
+
+
 def decode_wire_values(codes: jax.Array, scales_u8: jax.Array,
                        s_t: jax.Array) -> jax.Array:
     """One shard's packed payload -> fp32 residual values, (B,).
 
     ``codes`` (B/2,) uint8 nibble pairs, ``scales_u8`` (B/16,) raw E4M3
     bytes, ``s_t`` scalar fp32. Bitwise ``nvfp4_qdq`` of the residual: the
-    arithmetic decode is bit-exact to ``core.nvfp4.decode_e2m1_codes`` and
-    the per-block product ``vals * (s_b * s_t)`` is the QDQ's own
-    ``sign * q * scale`` (exact fp32 products of exact grid values).
+    arithmetic decodes are bit-exact to ``core.nvfp4.decode_e2m1_codes`` and
+    the fp8 cast. The payload is decoded as rows of 256 elements (zero
+    bytes pad the last row and are sliced off): a (B/2, 2) nibble
+    interleave would put a 2-wide minor dim in HBM, which the TPU pads to
+    128 lanes (64x the bytes; a vocab-sized bucket would not fit).
     """
-    vals = _decode_e2m1_arith(_unpack_tile(codes))
-    sc = jax.lax.bitcast_convert_type(
-        scales_u8, jnp.float8_e4m3fn).astype(jnp.float32) * s_t
-    return (vals.reshape(-1, BLOCK_SIZE) * sc[:, None]).reshape(-1)
+    half = codes.shape[-1]
+    pad = (-half) % (_FOLD_ROW // 2)
+    rows = jnp.pad(codes, (0, pad)).reshape(-1, _FOLD_ROW // 2)
+    srows = jnp.pad(scales_u8, (0, pad // 8)).reshape(
+        -1, _FOLD_ROW // BLOCK_SIZE)
+    return _decode_rows(rows, srows, s_t, _row_matrices()).reshape(-1)[
+        :2 * half]
 
 
 def _fold_means(mean: jax.Array, num_shards: int) -> jax.Array:
@@ -174,21 +206,20 @@ def _fold_packets_xla(codes: jax.Array, scales: jax.Array, amax: jax.Array,
 
 
 # --------------------------------------------------------------------------
-# Pallas kernel: sequential-grid fold, shards innermost
+# Pallas kernel: grid (row tiles, S), the output block accumulates
 # --------------------------------------------------------------------------
 
-def _packet_fold_kernel(codes_ref, scales_ref, st_ref, o_ref,
-                        *, num_shards: int):
-    """Grid (cols, S), shards innermost: the output block is the fold
-    accumulator (init at s == 0), exactly ``collectives._fold_kernel`` with
-    the decode pulled inside — codes and scales are read packed and the
-    residual exists only in registers."""
-    from jax.experimental import pallas as pl
+def _packet_fold_kernel(st_ref, codes_ref, scales_ref, lo_ref, hi_ref,
+                        ex_ref, o_ref, *, num_shards: int):
+    """Grid (row tiles, S), shards innermost: block (1, tr, 128) packed
+    bytes + (1, tr, 16) scale bytes of shard s are decoded and the output
+    block is the fold accumulator (``o = part`` at s == 0, then
+    ``o + part``) — exactly ``collectives._fold_kernel`` with the decode
+    pulled inside. Codes and scales are read packed and the residual
+    exists only in VMEM; VMEM and trace size do not grow with S."""
     s = pl.program_id(1)
-    vals = _decode_e2m1_arith(_unpack_tile(codes_ref[...]))[0]
-    sc = scales_ref[...][0].astype(jnp.float32) * st_ref[0, 0]
-    part = (vals.reshape(-1, BLOCK_SIZE) * sc[:, None]).reshape(-1) \
-        / num_shards
+    part = _decode_rows(codes_ref[0], scales_ref[0], st_ref[s],
+                        (lo_ref[...], hi_ref[...], ex_ref[...])) / num_shards
 
     @pl.when(s == 0)
     def _init():
@@ -202,32 +233,41 @@ def _packet_fold_kernel(codes_ref, scales_ref, st_ref, o_ref,
 def _fold_packets_pallas(codes: jax.Array, scales: jax.Array,
                          amax: jax.Array, mean: Optional[jax.Array],
                          num_shards: int, *,
-                         interpret: bool) -> Optional[jax.Array]:
-    """Pallas fold of (S, B/2)+(S, B/16) packed shards; None -> no tiling."""
-    from jax.experimental import pallas as pl
+                         interpret: bool) -> jax.Array:
+    """Pallas fold of (S, B/2)+(S, B/16) packed shards.
+
+    The payload is viewed as rows of 256 elements — (S, R, 128) code bytes
+    and (S, R, 16) scale bytes, whose last two dims Mosaic tiles whole —
+    zero-padded to whole row tiles (zero bytes decode to +0 and are sliced
+    off). Scale bytes stay uint8: v5e has no fp8 unit."""
     s_dim, half = codes.shape
     b = 2 * half
-    tile = None
-    for cand in _FOLD_TILE_COLS:
-        if b % cand == 0:
-            tile = cand
-            break
-    if tile is None:
-        return None
-    s_t = shard_tensor_scales(amax).reshape(s_dim, 1)
-    scales_f8 = jax.lax.bitcast_convert_type(scales, jnp.float8_e4m3fn)
+    rows = -(-b // _FOLD_ROW)
+    tile_r = next((t for t in _FOLD_TILE_ROWS if rows % t == 0),
+                  min(rows, _FOLD_TILE_ROWS[0]))
+    rows = -(-rows // tile_r) * tile_r
+    bp = rows * _FOLD_ROW
+    if bp != b:
+        codes = jnp.pad(codes, ((0, 0), (0, (bp - b) // 2)))
+        scales = jnp.pad(scales, ((0, 0), (0, (bp - b) // BLOCK_SIZE)))
+    n_bytes, n_scales = _FOLD_ROW // 2, _FOLD_ROW // BLOCK_SIZE
+    lo_m, hi_m, expand = _row_matrices()
+    const = lambda a: pl.BlockSpec(a.shape, lambda r, s: (0, 0))
     acc = pl.pallas_call(
         functools.partial(_packet_fold_kernel, num_shards=num_shards),
-        out_shape=jax.ShapeDtypeStruct((b,), jnp.float32),
-        grid=(b // tile, s_dim),
+        out_shape=jax.ShapeDtypeStruct((rows, _FOLD_ROW), jnp.float32),
+        grid=(rows // tile_r, s_dim),
         in_specs=[
-            pl.BlockSpec((1, tile // 2), lambda c, s: (s, c)),
-            pl.BlockSpec((1, tile // BLOCK_SIZE), lambda c, s: (s, c)),
-            pl.BlockSpec((1, 1), lambda c, s: (s, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, tile_r, n_bytes), lambda r, s: (s, r, 0)),
+            pl.BlockSpec((1, tile_r, n_scales), lambda r, s: (s, r, 0)),
+            const(lo_m), const(hi_m), const(expand),
         ],
-        out_specs=pl.BlockSpec((tile,), lambda c, s: (c,)),
+        out_specs=pl.BlockSpec((tile_r, _FOLD_ROW), lambda r, s: (r, 0)),
         interpret=interpret,
-    )(codes, scales_f8, s_t)
+    )(shard_tensor_scales(amax), codes.reshape(s_dim, rows, n_bytes),
+      scales.astype(jnp.uint8).reshape(s_dim, rows, n_scales),
+      lo_m, hi_m, expand).reshape(-1)[:b]
     if mean is not None:
         acc = acc + _fold_means(mean, num_shards)
     return acc
@@ -262,13 +302,8 @@ def fold_packets(codes: jax.Array, scales: jax.Array, amax: jax.Array,
             f"do not form S={num_shards} block-aligned shards")
         return fold_packets_reference(codes, scales, amax, mean, num_shards)
     if backend == "pallas":
-        acc = _fold_packets_pallas(codes, scales, amax, mean, num_shards,
-                                   interpret=interpret)
-        if acc is not None:
-            return acc
-        _wire_fold_fallback(
-            f"no Pallas column tiling for payload width {2*codes.shape[1]}")
-        backend = "xla"
+        return _fold_packets_pallas(codes, scales, amax, mean, num_shards,
+                                    interpret=interpret)
     if backend == "xla":
         return _fold_packets_xla(codes, scales, amax, mean, num_shards)
     return fold_packets_reference(codes, scales, amax, mean, num_shards)

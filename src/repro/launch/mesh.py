@@ -8,23 +8,18 @@ axis mapped across DCN.
 from __future__ import annotations
 
 import jax
-
-
-def _mesh_kwargs(n_axes: int) -> dict:
-    """axis_types arrived in newer jax; older versions default to Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over however many (possibly forced) host devices exist —
     used by multi-device tests and CPU examples."""
-    return jax.make_mesh((data, model), ("data", "model"), **_mesh_kwargs(2))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ALL_ARCHS, get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.policy import PrecisionPolicy
 from repro.models.layers import QuantCtx
 from repro.models.model import Model
@@ -263,6 +264,7 @@ def main() -> None:
                     help="Chrome-trace (Perfetto JSON) output of engine "
                          "phase spans (admit/prefill/decode/verify/...)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.static:
         run_static(args)

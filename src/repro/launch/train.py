@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ALL_ARCHS, get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data.pipeline import DataConfig, make_stream
 from repro.models.model import Model
 from repro.optim import adamw
@@ -84,6 +85,7 @@ def main() -> None:
                          "phase-split traced train step (single-device "
                          "path) and writes train-phase spans here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(message)s")
     cfg = reduced(args.arch) if args.reduced else get_config(args.arch)
@@ -151,11 +153,17 @@ def main() -> None:
                 getattr(raw_step, "wire_format", "packed"),
                 ws["num_buckets"], ws["total_bytes_per_step"],
                 ws["ratio_vs_bf16"])
-        step_fn = jax.jit(raw_step, donate_argnums=(0, 1))
+        step_fn = jax.jit(raw_step, donate_argnums=(0, 1),
+                          in_shardings=raw_step.in_shardings,
+                          out_shardings=raw_step.out_shardings)
 
         def init_fn():
-            return init_train_state(model, tcfg, jax.random.key(args.seed),
-                                    dp_shards=dp_shards)
+            # placed where the step keeps its state: the first step then
+            # compiles once and donates in place
+            return jax.device_put(
+                init_train_state(model, tcfg, jax.random.key(args.seed),
+                                 dp_shards=dp_shards),
+                raw_step.in_shardings[:2])
     elif tracer is not None:
         from repro.train.trainer import make_traced_train_step
         step_fn = make_traced_train_step(model, tcfg, tracer)

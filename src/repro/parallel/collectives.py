@@ -57,12 +57,14 @@ the fold actually reads.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from fnmatch import fnmatch
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from repro.core.formats import BLOCK_SIZE
 from repro.core.nvfp4 import (encode_e2m1_codes, pack_nibbles,
@@ -360,6 +362,9 @@ def _q_int8(x: jax.Array) -> jax.Array:
 
 
 _WIRE_TILE_COLS = (512, 256, 128, 64, 32, 16)
+# row tiles (of 128 lanes) of the decoded-shard Pallas fold: f32 sublane
+# multiples, at most 256 KiB per block
+_FOLD_TILE_ROWS = (512, 256, 128, 64, 32, 16, 8)
 
 
 def _wire_cols(n: int) -> Optional[int]:
@@ -548,10 +553,10 @@ def fold_packet_shards(recipe: CommRecipe, stacked: WirePacket,
 
 
 def _fold_kernel(x_ref, o_ref, *, num_shards: int):
-    """Sequential-grid left fold: o[c] = Σ_s x[s, c]/S in shard order."""
-    from jax.experimental import pallas as pl
+    """Grid (row tiles, S), shards innermost: the output block is the fold
+    accumulator, o = Σ_s x[s]/S in shard order (init at s == 0)."""
     s = pl.program_id(1)
-    part = x_ref[...].astype(jnp.float32)[0] / num_shards
+    part = x_ref[0].astype(jnp.float32) / num_shards
 
     @pl.when(s == 0)
     def _init():
@@ -562,29 +567,34 @@ def _fold_kernel(x_ref, o_ref, *, num_shards: int):
         o_ref[...] = o_ref[...] + part
 
 
-def _fold_shards_pallas(stacked: jax.Array,
-                        num_shards: int) -> Optional[jax.Array]:
-    """Pallas left fold of (S, B) decoded shards; None -> lax.scan path."""
+def _fold_shards_pallas(stacked: jax.Array, num_shards: int, *,
+                        interpret: Optional[bool] = None
+                        ) -> Optional[jax.Array]:
+    """Pallas left fold of (S, B) decoded shards; None -> lax.scan path.
+
+    Each shard is viewed as rows of 128 lanes (one row of B lanes when B
+    is not a 128-multiple) and a block holds one shard's row tile, so
+    Mosaic's rule for the last two block dims holds and VMEM does not
+    grow with S."""
     if stacked.ndim != 2:
         return None
     s_dim, b = stacked.shape
-    tile = None
-    for cand in (65536, 16384, 4096, 1024, 256, 128, 32, 16):
-        if b % cand == 0:
-            tile = cand
-            break
-    if tile is None:
+    lanes = 128 if b % 128 == 0 else b
+    rows = b // lanes
+    tile_r = next((t for t in _FOLD_TILE_ROWS if rows % t == 0), rows)
+    if tile_r * lanes > _FOLD_TILE_ROWS[0] * 128:
         return None
-    import functools
-    from jax.experimental import pallas as pl
+    if interpret is None:
+        interpret = _fused_interpret()
     return pl.pallas_call(
         functools.partial(_fold_kernel, num_shards=num_shards),
-        out_shape=jax.ShapeDtypeStruct((b,), jnp.float32),
-        grid=(b // tile, s_dim),
-        in_specs=[pl.BlockSpec((1, tile), lambda c, s: (s, c))],
-        out_specs=pl.BlockSpec((tile,), lambda c, s: (c,)),
-        interpret=_fused_interpret(),
-    )(stacked)
+        out_shape=jax.ShapeDtypeStruct((rows, lanes), jnp.float32),
+        grid=(rows // tile_r, s_dim),
+        in_specs=[pl.BlockSpec((1, tile_r, lanes),
+                               lambda r, s: (s, r, 0))],
+        out_specs=pl.BlockSpec((tile_r, lanes), lambda r, s: (r, 0)),
+        interpret=interpret,
+    )(stacked.reshape(s_dim, rows, lanes)).reshape(b)
 
 
 def encode_bucket(

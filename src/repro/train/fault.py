@@ -4,7 +4,11 @@
 
   * periodic checkpointing (atomic, retained),
   * failure detection (any exception from a step, incl. injected faults and
-    the NaN-loss guard) triggers restart from the latest checkpoint,
+    the NaN-loss guard) triggers restart from the latest checkpoint — except
+    an exception raised by the step function before it has completed once
+    in this process: that is the program failing to run (a compile
+    refusal, a program that does not fit the device), which a restart
+    would only replay, so it is re-raised at once,
   * deterministic data (pure function of step) means restarts replay the
     exact token stream — no loader state,
   * bounded restart budget (a real cluster supervisor would also re-slice
@@ -83,16 +87,21 @@ def run_supervised(
         log.info("resumed from step %d", start)
 
     step = start
+    stepped = False                 # has train_step completed in this process?
     while step < cfg.total_steps:
+        in_step = False
         try:
             t0 = time.monotonic()
             if injector is not None:
                 injector.check(step)
             batch = jax.tree.map(jnp.asarray, batch_fn(step))
+            in_step = True
             params, opt_state, metrics = train_step(
                 params, opt_state, batch, jax.random.fold_in(key, step)
             )
             loss = float(metrics["loss"])
+            in_step = False
+            stepped = True
             if cfg.nan_guard and not np.isfinite(loss):
                 raise RuntimeError(f"non-finite loss {loss} at step {step}")
             if cfg.step_timeout_s and (time.monotonic() - t0) > cfg.step_timeout_s:
@@ -107,6 +116,8 @@ def run_supervised(
                 checkpoint.save(cfg.ckpt_dir, step, params, opt_state,
                                 keep=cfg.keep)
         except Exception as e:  # noqa: BLE001 — supervisor catches everything
+            if in_step and not stepped:
+                raise
             restarts += 1
             log.warning("step %d failed (%s); restart %d", step, e, restarts)
             if restarts > cfg.max_restarts:

@@ -47,7 +47,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.policy import PrecisionPolicy
 from repro.models.layers import QuantCtx
@@ -358,8 +358,6 @@ def make_sharded_train_step(
     ``sharding.use_rules`` — the body runs under manual (shard_map) axes
     where ``with_sharding_constraint`` does not apply.
     """
-    from jax.experimental.shard_map import shard_map
-
     policy = resolve_policy(tcfg, model)
     grad_fn = jax.value_and_grad(
         make_loss_fn(model, policy, probe=tcfg.quant_probes), has_aux=True)
@@ -510,12 +508,22 @@ def make_sharded_train_step(
             raise ValueError(f"batch size {b} not divisible by "
                              f"dp_shards={S}")
         batch_specs = jax.tree.map(lambda _: P(dp_entry), batch)
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(pspecs, opt_specs, batch_specs, P()),
-                       out_specs=(pspecs, opt_specs, P()),
-                       check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(pspecs, opt_specs, batch_specs, P()),
+                           out_specs=(pspecs, opt_specs, P()),
+                           check_vma=False)
         return fn(params, opt_state, batch, step_key)
 
+    # Shardings for jax.jit: inputs land where shard_map's out_specs put
+    # the outputs, so donated params/optimizer buffers alias (host or
+    # single-device inputs, e.g. a restored checkpoint, are placed first)
+    named = lambda t: jax.tree.map(lambda sp: NamedSharding(mesh, sp), t,
+                                   is_leaf=lambda x: isinstance(x, P))
+    state_shardings = (named(pspecs), named(opt_specs))
+    train_step.in_shardings = (*state_shardings,
+                               NamedSharding(mesh, P(dp_entry)),
+                               NamedSharding(mesh, P()))
+    train_step.out_shardings = (*state_shardings, NamedSharding(mesh, P()))
     train_step.mesh = mesh
     train_step.dp_shards = S
     train_step.comm_layout = layout

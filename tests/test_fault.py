@@ -93,3 +93,35 @@ def test_resume_from_existing_checkpoint(tmp_path):
     out = run_supervised(step_fn, init_fn, batch_fn, jax.random.key(1), sup2)
     assert out["restarts"] == 0
     assert len(out["losses"]) == 2  # only steps 8..9 executed
+
+
+def test_step_failure_before_first_step_reraises_once(tmp_path):
+    """A step function that cannot run at all (a compile refusal on the
+    first call) surfaces at once: re-raised, not restarted."""
+    _, init_fn, batch_fn, sup = _setup(tmp_path)
+    calls = []
+
+    def refused(params, opt_state, batch, key):
+        calls.append(1)
+        raise ValueError("compiler refused the program")
+
+    with pytest.raises(ValueError, match="compiler refused"):
+        run_supervised(refused, init_fn, batch_fn, jax.random.key(1), sup)
+    assert len(calls) == 1
+    assert checkpoint.latest_step(str(tmp_path)) is None
+
+
+def test_step_failure_after_first_step_restarts(tmp_path):
+    """Once the step function has completed in this process, its own
+    exceptions go through the restart path like injected faults."""
+    step_fn, init_fn, batch_fn, sup = _setup(tmp_path, total=8, ckpt_every=4)
+    failed = []
+
+    def flaky(params, opt_state, batch, key):
+        if len(failed) == 0 and int(opt_state["step"]) == 5:
+            failed.append(1)
+            raise RuntimeError("transient device error")
+        return step_fn(params, opt_state, batch, key)
+
+    out = run_supervised(flaky, init_fn, batch_fn, jax.random.key(1), sup)
+    assert out["restarts"] == 1 and out["steps"] == 8

@@ -286,3 +286,61 @@ def test_wire_fused_encode_and_fold_bitwise():
     for s in range(4):
         acc = acc + stacked[s] / 4
     assert jnp.array_equal(folded_k, acc)
+
+
+@pytest.mark.parametrize("shape", [(64, 384), (128, 96)])
+def test_mosaic_forms_interpreted_match_reference(shape, monkeypatch):
+    """The forms the compiled kernels use (block-diagonal MXU rotation,
+    lane-roll block max, arithmetic E4M3 rounding, MXU nibble pairing),
+    run interpreted, are bitwise the unfused reference on dyadic inputs
+    (every fp32 sum exact) — in both orientations, RN and SR, QDQ and
+    pack. The chip compiles these forms; the CPU suite otherwise runs the
+    split-lane twins."""
+    import repro.kernels.fused as F
+
+    monkeypatch.setattr(F, "_split_lanes", lambda interpret: False)
+    jax.clear_caches()
+    x = _dyadic(shape, seed=21)
+    bits = _bits(shape, seed=22)
+
+    @jax.jit
+    def both(xx, bb):
+        mu = column_mean_2d(xx)
+        out = {
+            "lanes": (center_hadamard_qdq_2d(xx, mu, None, None, rotate=True),
+                      ref.center_hadamard_qdq_2d_ref(xx, mu, None,
+                                                     rotate=True)),
+            "lanes_sr": (center_hadamard_qdq_2d(xx, mu, None, bb,
+                                                rotate=True),
+                         ref.center_hadamard_qdq_2d_ref(xx, mu, bb,
+                                                        rotate=True)),
+            "sublanes": (center_hadamard_qdq_2d(xx, mu, None, None,
+                                                rotate=True, block_axis=0),
+                         ref.center_hadamard_qdq_2d_ref(
+                             xx.T, mu.T, None, rotate=True).T),
+            "amax": (fused_amax_2d(xx, mu, rotate=True, tile_l=32),
+                     jnp.max(jnp.abs(ref._preprocess_ref(xx, mu, True)))
+                     .reshape(1, 1)),
+        }
+        # the multi-tile (two-pass) kernel: an explicit amax skips the
+        # single-tile fast path
+        amax = out["amax"][1]
+        out["two_pass"] = (center_hadamard_qdq_2d(xx, mu, amax, None,
+                                                  rotate=True, tile_l=16,
+                                                  tile_m=shape[1] // 2
+                                                  if shape[1] % 256 == 0
+                                                  else shape[1]),
+                           out["lanes"][1])
+        pk, sc, st = center_hadamard_pack_2d(xx, mu, None, None, rotate=True)
+        rpk, rsc, rst = ref.center_hadamard_pack_2d_ref(xx, mu, None,
+                                                        rotate=True)
+        out["pack"] = (pk, rpk)
+        out["pack_scales"] = (sc.astype(jnp.float32), rsc.astype(jnp.float32))
+        out["pack_s_t"] = (st, rst)
+        return out
+
+    try:
+        for name, (got, want) in both(x, bits).items():
+            assert jnp.array_equal(got, want), name
+    finally:
+        jax.clear_caches()

@@ -14,6 +14,7 @@ import warnings
 
 import numpy as np
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import pytest
 
@@ -180,6 +181,84 @@ def test_gqa_pallas_interpret_matches_xla_twin():
     np.testing.assert_allclose(np.asarray(run("pallas")),
                                np.asarray(run("xla")),
                                rtol=2e-6, atol=2e-6)
+
+
+@pytest.mark.parametrize("centered", [True, False])
+def test_gqa_pallas_partial_page_group_matches_xla_twin(centered):
+    """Both backends step the online softmax over the same page groups;
+    when the capacity is not a whole number of groups the kernel pads the
+    last group, and the padded pages (and committed-but-future ones) must
+    add nothing."""
+    p, n, hd, b = 16, 2, 32, 2
+    T = 8 * p + 3                                   # 9 pages: groups of 8
+    adapter = QuantizedKVAdapter(num_kv_heads=n, head_dim=hd, page_size=p,
+                                 centered=centered)
+    kv = _rand_kv(jax.random.key(10), b, T, n, hd, bias=0.3)
+    cache = _fill_kv_cache(adapter, kv)
+    assert cache["codes"].shape[1] == 9
+    pos = jnp.asarray([T - 1, 5 * p + 2], jnp.int32)
+    q = jax.random.normal(jax.random.key(11), (b, 1, 4, hd), jnp.bfloat16)
+
+    def run(backend):
+        return paged_attend_gqa(
+            q, cache["codes"], cache["scales"], cache["pamax"],
+            cache.get("mean"), cache["tail"], pos, page_size=p,
+            backend=backend, interpret=True)
+
+    np.testing.assert_allclose(np.asarray(run("pallas")),
+                               np.asarray(run("xla")),
+                               rtol=2e-6, atol=2e-6)
+
+
+def _dot_precisions(jaxpr):
+    """Precision params of every dot_general in ``jaxpr``, sub-jaxprs
+    (loop bodies) included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"])
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, jex_core.ClosedJaxpr):
+                    out += _dot_precisions(sub.jaxpr)
+                elif isinstance(sub, jex_core.Jaxpr):
+                    out += _dot_precisions(sub)
+    return out
+
+
+@pytest.mark.parametrize("path", ["gqa", "gqa_span", "mla"])
+def test_read_contractions_pinned_to_highest(path):
+    """Every contraction of the paged read (XLA twin, tail page, span,
+    MLA) is at Precision.HIGHEST, as the Pallas kernel's dots are. A TPU's
+    default f32 matmul rounds operands to bf16 — the softmax weights of the
+    tail's p @ v among them — and XLA:CPU ignores precision, so only the
+    traced graph can show a contraction left at the caller's default."""
+    p, n, hd, b, S = 16, 2, 32, 2, 3
+    np_ = 3
+    pos = jnp.full((b,), 2 * p + 3, jnp.int32)
+    if path == "mla":
+        rkv, dr, nh = 32, 8, 4
+        args = (jnp.zeros((b, nh, rkv)), jnp.zeros((b, nh, dr)),
+                jnp.zeros((b, np_, p, rkv // 2), jnp.uint8),
+                jnp.zeros((b, np_, p, rkv // 16), jnp.float8_e4m3fn),
+                jnp.ones((b, np_)), jnp.zeros((b, np_, rkv), jnp.bfloat16),
+                jnp.zeros((b, np_ * p, dr)), jnp.zeros((b, p, rkv)), pos)
+        fn = lambda *a: paged_attend_mla(*a, page_size=p, sm_scale=0.1)
+    else:
+        s = S if path == "gqa_span" else 1
+        span = jnp.zeros((b, S, 2, n, hd)) if path == "gqa_span" else None
+        args = (jnp.zeros((b, s, 4, hd), jnp.bfloat16),
+                jnp.zeros((b, np_, p, 2, n, hd // 2), jnp.uint8),
+                jnp.zeros((b, np_, p, 2, n, hd // 16), jnp.float8_e4m3fn),
+                jnp.ones((b, np_, 2)),
+                jnp.zeros((b, np_, 2, n, hd), jnp.bfloat16),
+                jnp.zeros((b, p, 2, n, hd), jnp.bfloat16), pos)
+        fn = lambda *a: paged_attend_gqa(*a, page_size=p, span=span,
+                                         backend="xla")
+    precisions = _dot_precisions(jax.make_jaxpr(fn)(*args).jaxpr)
+    highest = jax.lax.Precision.HIGHEST
+    assert precisions
+    assert all(pr == (highest, highest) for pr in precisions), precisions
 
 
 def test_adversarial_large_mean_tiny_residual():
