@@ -36,6 +36,8 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
+
 from .averis import split_mean
 from .hadamard import hadamard_tiles
 from .nvfp4 import nvfp4_qdq
@@ -453,42 +455,45 @@ def apply_stages(
     if getattr(cfg, "backend", "stages") == "fused":
         kind, how = _classify_fused(operand, cfg, t)
         if kind == "fuse":
-            return _apply_fused(t, how, sr_key=sr_key, splits=splits)
+            with jax.named_scope(scopes.FUSED):
+                return _apply_fused(t, how, sr_key=sr_key, splits=splits)
         if kind == "fallback":
             _fused_fallback(how)
     v = t
     for st in operand.stages:
-        if isinstance(st, Center):
-            # Memoize only source-level splits (Center as first stage): the
-            # mean/residual pair of one tensor is computed once per GeMM.
-            memoizable = splits is not None and v is t
-            if memoizable and st.token_axis in splits:
-                mu, res = splits[st.token_axis]
-                if res is None and st.take == "residual":
-                    # memo written by the fused backend (which never
-                    # materializes the residual): rebuild it from the
-                    # shared mean so both backends center identically
-                    res = (v.astype(jnp.float32)
-                           - jnp.expand_dims(mu.astype(jnp.float32),
-                                             st.token_axis)).astype(v.dtype)
-                    splits[st.token_axis] = (mu, res)
-            else:
-                mu, res = split_mean(v, token_axis=st.token_axis)
-                if memoizable:
-                    splits[st.token_axis] = (mu, res)
-            v = res if st.take == "residual" else mu
-        elif isinstance(st, Hadamard):
-            v = _hadamard_or_skip(v, st.axis)
-        elif isinstance(st, Quantize):
-            if operand.weight and not cfg.quantize_weights:
-                continue             # bf16 weights (A4G4 without W4)
-            use_sr = st.sr and cfg.sr_grad
-            v = nvfp4_qdq(v, st.axis, sr=use_sr,
-                          key=sr_key if use_sr else None,
-                          block_size=cfg.block_size,
-                          compute_dtype=jnp.dtype(cfg.qdq_dtype))
-        else:                        # pragma: no cover
-            raise TypeError(f"unknown stage {st!r}")
+        with jax.named_scope(scopes.STAGE[type(st).__name__]):
+            if isinstance(st, Center):
+                # Memoize only source-level splits (Center as first
+                # stage): the mean/residual pair of one tensor is
+                # computed once per GeMM.
+                memoizable = splits is not None and v is t
+                if memoizable and st.token_axis in splits:
+                    mu, res = splits[st.token_axis]
+                    if res is None and st.take == "residual":
+                        # memo written by the fused backend (which never
+                        # materializes the residual): rebuild it from the
+                        # shared mean so both backends center identically
+                        res = (v.astype(jnp.float32) - jnp.expand_dims(
+                            mu.astype(jnp.float32), st.token_axis)
+                               ).astype(v.dtype)
+                        splits[st.token_axis] = (mu, res)
+                else:
+                    mu, res = split_mean(v, token_axis=st.token_axis)
+                    if memoizable:
+                        splits[st.token_axis] = (mu, res)
+                v = res if st.take == "residual" else mu
+            elif isinstance(st, Hadamard):
+                v = _hadamard_or_skip(v, st.axis)
+            elif isinstance(st, Quantize):
+                if operand.weight and not cfg.quantize_weights:
+                    continue             # bf16 weights (A4G4 without W4)
+                use_sr = st.sr and cfg.sr_grad
+                v = nvfp4_qdq(v, st.axis, sr=use_sr,
+                              key=sr_key if use_sr else None,
+                              block_size=cfg.block_size,
+                              compute_dtype=jnp.dtype(cfg.qdq_dtype))
+            else:                        # pragma: no cover
+                raise TypeError(f"unknown stage {st!r}")
     return v
 
 
@@ -523,26 +528,28 @@ def execute_terms(
         return memo[mk]
 
     total = None
-    for term in terms:
-        a = value(term.lhs, lhs, "lhs")
-        b = value(term.rhs, rhs, "rhs")
-        if term.kind == "matmul":
-            if gemm == "fwd":
-                v = jnp.dot(a, b, preferred_element_type=acc)
-            elif gemm == "dx":
-                v = jnp.dot(a, b.T, preferred_element_type=acc)
-            else:                    # dw
-                v = jnp.dot(a.T, b, preferred_element_type=acc)
-        elif term.kind == "mean_row":
-            bt = b if gemm == "fwd" else b.T
-            v = jnp.dot(a, bt, preferred_element_type=acc)[None, :]
-        else:                        # rank1 (dw): l · outer(μ̄_X, μ̄_D)
-            assert gemm == "dw", "rank1 terms are weight-grad only"
-            v = lhs.shape[0] * jnp.outer(
-                a.astype(jnp.float32), b.astype(jnp.float32)
-            ).astype(acc)
-        total = v if total is None else total + v
-    return total.astype(out_dtype)
+    with jax.named_scope(scopes.QGEMM[gemm]):
+        for term in terms:
+            a = value(term.lhs, lhs, "lhs")
+            b = value(term.rhs, rhs, "rhs")
+            with jax.named_scope(scopes.TERM[term.kind]):
+                if term.kind == "matmul":
+                    if gemm == "fwd":
+                        v = jnp.dot(a, b, preferred_element_type=acc)
+                    elif gemm == "dx":
+                        v = jnp.dot(a, b.T, preferred_element_type=acc)
+                    else:                    # dw
+                        v = jnp.dot(a.T, b, preferred_element_type=acc)
+                elif term.kind == "mean_row":
+                    bt = b if gemm == "fwd" else b.T
+                    v = jnp.dot(a, bt, preferred_element_type=acc)[None, :]
+                else:            # rank1 (dw): l · outer(μ̄_X, μ̄_D)
+                    assert gemm == "dw", "rank1 terms are weight-grad only"
+                    v = lhs.shape[0] * jnp.outer(
+                        a.astype(jnp.float32), b.astype(jnp.float32)
+                    ).astype(acc)
+                total = v if total is None else total + v
+        return total.astype(out_dtype)
 
 
 # --------------------------------------------------------------------------

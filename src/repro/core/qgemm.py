@@ -41,6 +41,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
+
 from .formats import MODES
 from .pipeline import GemmPlan, PLANS, apply_stages, execute_terms, plan_for
 
@@ -121,12 +123,14 @@ def _prepared_weights(
     tensor-level amax.
     """
     out = []
-    for spec in plan.weight_specs(gemm):
-        if per_expert:
-            val = jax.vmap(lambda we, _s=spec: _prepare_weight(we, _s, cfg))(w)
-        else:
-            val = _prepare_weight(w, spec, cfg)
-        out.append(val)
+    with jax.named_scope(scopes.QGEMM_WEIGHTS):
+        for spec in plan.weight_specs(gemm):
+            if per_expert:
+                val = jax.vmap(
+                    lambda we, _s=spec: _prepare_weight(we, _s, cfg))(w)
+            else:
+                val = _prepare_weight(w, spec, cfg)
+            out.append(val)
     return tuple(out)
 
 
@@ -273,15 +277,16 @@ def prepared_weight_stack(
     plan = plan_for(cfg.mode)
     s0, s1 = seg
     out = []
-    for gemm in ("fwd", "dx"):
-        vals = []
-        for spec in plan.weight_specs(gemm):
-            wseg = stacked[s0:s1].astype(compute_dtype)
-            prep = lambda we, _s=spec: _prepare_weight(we, _s, cfg)
-            if per_expert:
-                prep = jax.vmap(prep)            # expert axis under layer axis
-            vals.append(jax.lax.stop_gradient(jax.vmap(prep)(wseg)))
-        out.append(tuple(vals))
+    with jax.named_scope(scopes.QGEMM_WEIGHTS):
+        for gemm in ("fwd", "dx"):
+            vals = []
+            for spec in plan.weight_specs(gemm):
+                wseg = stacked[s0:s1].astype(compute_dtype)
+                prep = lambda we, _s=spec: _prepare_weight(we, _s, cfg)
+                if per_expert:
+                    prep = jax.vmap(prep)    # expert axis under layer axis
+                vals.append(jax.lax.stop_gradient(jax.vmap(prep)(wseg)))
+            out.append(tuple(vals))
     return tuple(out)
 
 
@@ -289,12 +294,13 @@ def prepared_weight_single(w: jax.Array, cfg: QuantConfig, compute_dtype):
     """Prepared ``(wq_fwd_tuple, wq_dx_tuple)`` for one unstacked weight
     (the lm_head path of ``Model.prepare_qweights``)."""
     plan = plan_for(cfg.mode)
-    wc = w.astype(compute_dtype)
-    return tuple(
-        tuple(jax.lax.stop_gradient(_prepare_weight(wc, spec, cfg))
-              for spec in plan.weight_specs(gemm))
-        for gemm in ("fwd", "dx")
-    )
+    with jax.named_scope(scopes.QGEMM_WEIGHTS):
+        wc = w.astype(compute_dtype)
+        return tuple(
+            tuple(jax.lax.stop_gradient(_prepare_weight(wc, spec, cfg))
+                  for spec in plan.weight_specs(gemm))
+            for gemm in ("fwd", "dx")
+        )
 
 
 def gemm_plan_summary(cfg: QuantConfig, x_shape, w_shape) -> Dict:

@@ -18,7 +18,8 @@ supervisor here is the per-job logic that consumes it.
 from __future__ import annotations
 
 import argparse
-import dataclasses
+import contextlib
+import itertools
 import logging
 
 import jax
@@ -33,7 +34,28 @@ from repro.train.fault import SupervisorConfig, run_supervised
 from repro.train.trainer import TrainConfig, init_train_state, make_train_step
 
 
-def main() -> None:
+def span_steps(step_fn, tracer=None, profile: bool = False):
+    """``step_fn`` with each call in a ``train.step`` span that ends when
+    the step's outputs are ready: a ``ChromeTracer`` span (which also lands
+    in a profiler trace) and, with ``profile``, a
+    ``jax.profiler.StepTraceAnnotation`` numbering the steps."""
+    calls = itertools.count()
+
+    def step(*args):
+        k = next(calls)
+        with contextlib.ExitStack() as spans:
+            if profile:
+                spans.enter_context(jax.profiler.StepTraceAnnotation(
+                    "train.step", step_num=k))
+            if tracer is not None:
+                spans.enter_context(tracer.span("train.step", cat="train",
+                                                step=k))
+            return jax.block_until_ready(step_fn(*args))
+
+    return step
+
+
+def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b", choices=ALL_ARCHS)
     ap.add_argument("--reduced", action="store_true",
@@ -81,10 +103,14 @@ def main() -> None:
                     help="JSONL sink path for per-step telemetry records "
                          "(implies --telemetry)")
     ap.add_argument("--trace-out", default="",
-                    help="Chrome-trace (Perfetto JSON) output: runs the "
-                         "phase-split traced train step (single-device "
-                         "path) and writes train-phase spans here")
-    args = ap.parse_args()
+                    help="Chrome-trace (Perfetto JSON) output: one "
+                         "train.step span per step of the fused step")
+    ap.add_argument("--profile-dir", default="",
+                    help="write a jax.profiler trace (.xplane.pb) of the "
+                         "step loop under this directory, each step a "
+                         "train.step span; device ops carry the step's "
+                         "named scopes (repro/obs/scopes.py)")
+    args = ap.parse_args(argv)
     enable_compile_cache()
 
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(message)s")
@@ -116,10 +142,6 @@ def main() -> None:
     sharded = n_dev > 1 or dp_shards > 1 or args.comm_recipe
     tracer = None
     if args.trace_out:
-        if sharded:
-            raise SystemExit("--trace-out runs the phase-split traced step, "
-                             "which is single-device; drop the sharding "
-                             "flags or the trace")
         from repro.obs import ChromeTracer
         tracer = ChromeTracer(process_name=f"train:{args.arch}")
     hub = None
@@ -164,12 +186,6 @@ def main() -> None:
                 init_train_state(model, tcfg, jax.random.key(args.seed),
                                  dp_shards=dp_shards),
                 raw_step.in_shardings[:2])
-    elif tracer is not None:
-        from repro.train.trainer import make_traced_train_step
-        step_fn = make_traced_train_step(model, tcfg, tracer)
-
-        def init_fn():
-            return init_train_state(model, tcfg, jax.random.key(args.seed))
     else:
         step_fn = jax.jit(make_train_step(model, tcfg), donate_argnums=(0, 1))
 
@@ -205,16 +221,29 @@ def main() -> None:
                   f"lr {float(metrics.get('lr', 0)):.2e}{health}",
                   flush=True)
 
+    if tracer is not None or args.profile_dir:
+        step_fn = span_steps(step_fn, tracer, profile=bool(args.profile_dir))
     sup = SupervisorConfig(total_steps=args.steps, ckpt_every=args.ckpt_every,
                            ckpt_dir=args.ckpt_dir)
-    out = run_supervised(step_fn, init_fn, stream.batch,
-                         jax.random.key(args.seed + 1), sup,
-                         on_metrics=on_metrics)
+    profiling = contextlib.nullcontext()
+    if args.profile_dir:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0     # the spans, not every Python call
+        profiling = jax.profiler.trace(args.profile_dir,
+                                       profiler_options=opts)
+    with profiling:
+        out = run_supervised(step_fn, init_fn, stream.batch,
+                             jax.random.key(args.seed + 1), sup,
+                             on_metrics=on_metrics)
     if tracer is not None:
         tracer.save(args.trace_out)
         logging.info("wrote Chrome trace (%d events) to %s — load in "
                      "chrome://tracing or ui.perfetto.dev",
                      len(tracer.events), args.trace_out)
+    if args.profile_dir:
+        logging.info("wrote the profiler trace under %s (XProf, "
+                     "TensorBoard or jax.profiler.ProfileData)",
+                     args.profile_dir)
     if hub is not None and args.telemetry_out:
         hub.emit("train.summary", **{
             k: v for k, v in hub.snapshot()["gauges"].items()})

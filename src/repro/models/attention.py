@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.obs import scopes
 from repro.parallel.sharding import constrain
 from .cache import dense_gqa_adapter, dense_mla_adapter
 from .layers import Param, QuantCtx, apply_rope, rms_norm, rope_angles
@@ -63,24 +64,26 @@ def attention_core(
     nkv = k.shape[2]
     hv = v.shape[-1]  # may differ from hd (MLA: qk vs v head dims)
     g = nh // nkv
-    qg = q.reshape(b, sq, nkv, g, hd)
-    if sq <= q_chunk or sq % q_chunk != 0:
-        out = _attend_block(qg, k, v, qpos, kpos, causal, softmax_dtype)
-    else:
-        nc = sq // q_chunk
-        qc = qg.reshape(b, nc, q_chunk, nkv, g, hd)
-        pc = qpos.reshape(b, nc, q_chunk)
+    with jax.named_scope(scopes.ATTN_CORE):
+        qg = q.reshape(b, sq, nkv, g, hd)
+        if sq <= q_chunk or sq % q_chunk != 0:
+            out = _attend_block(qg, k, v, qpos, kpos, causal, softmax_dtype)
+        else:
+            nc = sq // q_chunk
+            qc = qg.reshape(b, nc, q_chunk, nkv, g, hd)
+            pc = qpos.reshape(b, nc, q_chunk)
 
-        def body(_, xs):
-            qi, pi = xs
-            return None, _attend_block(qi, k, v, pi, kpos, causal,
-                                       softmax_dtype)
+            def body(_, xs):
+                qi, pi = xs
+                return None, _attend_block(qi, k, v, pi, kpos, causal,
+                                           softmax_dtype)
 
-        _, outs = jax.lax.scan(
-            body, None, (jnp.moveaxis(qc, 1, 0), jnp.moveaxis(pc, 1, 0))
-        )
-        out = jnp.moveaxis(outs, 0, 1).reshape(b, nc * q_chunk, nkv, g, hv)
-    return out.reshape(b, sq, nh, hv)
+            _, outs = jax.lax.scan(
+                body, None, (jnp.moveaxis(qc, 1, 0), jnp.moveaxis(pc, 1, 0))
+            )
+            out = jnp.moveaxis(outs, 0, 1).reshape(b, nc * q_chunk, nkv, g,
+                                                   hv)
+        return out.reshape(b, sq, nh, hv)
 
 
 # --------------------------------------------------------------------------

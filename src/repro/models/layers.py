@@ -147,10 +147,11 @@ class QuantCtx:
         cfg = self.resolve(role)
         if self.probes is not None:
             self._probe(x, site, role, cfg)
-        return qgemm(x, w, cfg,
-                     jax.random.fold_in(self.key, site),
-                     prepared=prepared if prepared is not None
-                     else self._prep(site))
+        with jax.named_scope(role or "default"):
+            return qgemm(x, w, cfg,
+                         jax.random.fold_in(self.key, site),
+                         prepared=prepared if prepared is not None
+                         else self._prep(site))
 
     def gemm_expert(self, x: jax.Array, w: jax.Array, site: int,
                     role: Optional[str] = None) -> jax.Array:
@@ -162,9 +163,10 @@ class QuantCtx:
             # flattened across experts (the quantizer sees per-expert
             # blocks, but the site-level health signal is the pooled one).
             self._probe(x.reshape(-1, x.shape[-1]), site, role, cfg)
-        return qgemm_expert(x, w, cfg,
-                            jax.random.fold_in(self.key, site),
-                            prepared=self._prep(site))
+        with jax.named_scope(role or "default"):
+            return qgemm_expert(x, w, cfg,
+                                jax.random.fold_in(self.key, site),
+                                prepared=self._prep(site))
 
     def child(self, tag: int) -> "QuantCtx":
         return QuantCtx(self.policy, jax.random.fold_in(self.key, tag),

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core.qgemm import QuantConfig
+from repro.obs import scopes
 from repro.parallel.sharding import constrain
 from .cache import default_adapter, grow_caches
 from .layers import (
@@ -432,9 +433,11 @@ class Model:
             lg = lg[:, :-1]
         else:
             targets = batch["labels"]
-        logz = jax.scipy.special.logsumexp(lg, axis=-1)
-        gold = jnp.take_along_axis(lg, targets[..., None], axis=-1)[..., 0]
-        ce = jnp.mean(logz - gold)
+        with jax.named_scope(scopes.LOSS):
+            logz = jax.scipy.special.logsumexp(lg, axis=-1)
+            gold = jnp.take_along_axis(lg, targets[..., None],
+                                       axis=-1)[..., 0]
+            ce = jnp.mean(logz - gold)
         total = ce + cfg.aux_loss_coef * aux
         return total, {"ce": ce, "aux": aux}
 

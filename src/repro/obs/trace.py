@@ -5,8 +5,15 @@ https://ui.perfetto.dev load directly: complete spans (``ph: "X"``) with
 microsecond timestamps, plus instant events (``ph: "i"``) for point
 occurrences like prefix-cache pool hits. Spans wrap *host-observed* phases —
 callers bracket device work with ``jax.block_until_ready`` so async dispatch
-cannot under-report durations (see ``serve/engine.py`` and
-``train/trainer.make_traced_train_step``).
+cannot under-report durations (see ``serve/engine.py`` and the
+``train.step`` span of ``launch/train.py``).
+
+Every span and instant also enters a ``jax.profiler.TraceAnnotation`` of
+the same name, so under a profiler session (``launch/train.py
+--profile-dir``) the host spans land in the ``.xplane.pb`` on the device
+trace's clock. What runs *inside* a jitted step is named there by the
+step's ``jax.named_scope``s (``repro/obs/scopes.py``), which reach the
+device operations as their HLO ``op_name``.
 """
 from __future__ import annotations
 
@@ -14,6 +21,8 @@ import json
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Set
+
+import jax
 
 
 class ChromeTracer:
@@ -36,7 +45,8 @@ class ChromeTracer:
         """Complete-event span around a ``with`` block."""
         ts = self._now_us()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(name):
+                yield
         finally:
             self.events.append({
                 "ph": "X", "name": name, "cat": cat, "ts": ts,
@@ -46,6 +56,8 @@ class ChromeTracer:
 
     def instant(self, name: str, cat: str = "engine", tid: int = 0,
                 **args: Any) -> None:
+        with jax.profiler.TraceAnnotation(name):
+            pass
         self.events.append({
             "ph": "i", "s": "t", "name": name, "cat": cat,
             "ts": self._now_us(), "pid": 0, "tid": tid, "args": args,

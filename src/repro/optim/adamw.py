@@ -14,6 +14,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
+
 
 @dataclasses.dataclass(frozen=True)
 class OptimizerConfig:
@@ -83,11 +85,12 @@ def apply_updates(
 ):
     """One AdamW step. Returns (new_params, new_state, metrics)."""
     metrics: Dict[str, jax.Array] = {}
-    if cfg.clip_norm > 0:
-        grads, gnorm = clip_by_global_norm(grads, cfg.clip_norm)
-        metrics["grad_norm"] = gnorm
-    else:
-        metrics["grad_norm"] = global_norm(grads)
+    with jax.named_scope(scopes.ADAMW_CLIP):
+        if cfg.clip_norm > 0:
+            grads, gnorm = clip_by_global_norm(grads, cfg.clip_norm)
+            metrics["grad_norm"] = gnorm
+        else:
+            metrics["grad_norm"] = global_norm(grads)
     if grad_transform is not None:
         grads, state = grad_transform(grads, state)
 
@@ -114,8 +117,9 @@ def apply_updates(
     flat_m = treedef.flatten_up_to(state["m"])
     flat_v = treedef.flatten_up_to(state["v"])
     flat_mask = treedef.flatten_up_to(mask)
-    out = [upd(p, g, m, v, w) for p, g, m, v, w in
-           zip(flat_p, flat_g, flat_m, flat_v, flat_mask)]
+    with jax.named_scope(scopes.ADAMW_UPDATE):
+        out = [upd(p, g, m, v, w) for p, g, m, v, w in
+               zip(flat_p, flat_g, flat_m, flat_v, flat_mask)]
     new_params = treedef.unflatten([o[0] for o in out])
     new_m = treedef.unflatten([o[1] for o in out])
     new_v = treedef.unflatten([o[2] for o in out])

@@ -224,74 +224,6 @@ def make_train_step(
     return train_step
 
 
-def make_traced_train_step(model: Model, tcfg: TrainConfig, tracer):
-    """Single-device step split into separately-jitted, span-wrapped phases.
-
-    The four phases of ``make_train_step``'s fused body — prepare_qweights,
-    microbatch scan, encode/reduce/fold (clip + the grad-compression
-    codec), optimizer — each run under a ``repro.obs.trace.ChromeTracer``
-    span bracketed by ``jax.block_until_ready``, so the trace shows real
-    phase durations instead of async dispatch time.
-
-    Numerically identical to the fused step: phase 3 replicates
-    ``adamw.apply_updates``' clip -> grad_transform ordering exactly, and
-    phase 4 re-runs ``apply_updates`` with clipping disabled and no
-    transform (its stale ``grad_norm`` is overwritten with phase 3's).
-    The split costs one extra device round-trip per phase — a tracing
-    mode, not the production step.
-    """
-    if tcfg.comm_recipe:
-        raise ValueError("the traced step is single-device; comm_recipe "
-                         "selects the sharded DP wire")
-    policy = resolve_policy(tcfg, model)
-    grad_fn = jax.value_and_grad(
-        make_loss_fn(model, policy, probe=tcfg.quant_probes), has_aux=True)
-    shard_grads = jax.jit(_make_shard_grads(model, tcfg, grad_fn))
-    prepare = jax.jit(lambda p: model.prepare_qweights(p, policy))
-    transform = None
-    if tcfg.grad_compression not in ("", "none"):
-        transform = coll.make_comm_transform(
-            recipe=tcfg.grad_compression, policy=policy,
-            bucket_mb=tcfg.comm_bucket_mb)
-
-    def _encode_reduce_fold(grads, opt_state):
-        metrics: Dict[str, jax.Array] = {}
-        if tcfg.optimizer.clip_norm > 0:
-            grads, gnorm = adamw.clip_by_global_norm(
-                grads, tcfg.optimizer.clip_norm)
-            metrics["grad_norm"] = gnorm
-        else:
-            metrics["grad_norm"] = adamw.global_norm(grads)
-        if transform is not None:
-            grads, opt_state = transform(grads, opt_state)
-        return grads, opt_state, metrics
-
-    encode = jax.jit(_encode_reduce_fold)
-    nocip = dataclasses.replace(tcfg.optimizer, clip_norm=0.0)
-    apply_fn = jax.jit(
-        lambda p, g, s: adamw.apply_updates(p, g, s, nocip))
-
-    def train_step(params, opt_state, batch, step_key):
-        with tracer.span("train.prepare_qweights", cat="train"):
-            qweights = prepare(params)
-            jax.block_until_ready(qweights)
-        with tracer.span("train.microbatch_scan", cat="train"):
-            loss, metrics, grads = shard_grads(params, batch, step_key,
-                                               qweights)
-            jax.block_until_ready((loss, grads))
-        with tracer.span("train.encode_reduce_fold", cat="train"):
-            grads, opt_state, gmetrics = encode(grads, opt_state)
-            jax.block_until_ready(grads)
-        with tracer.span("train.optimizer", cat="train"):
-            params, opt_state, opt_metrics = apply_fn(params, grads,
-                                                      opt_state)
-            jax.block_until_ready(params)
-        out = {"loss": loss, **metrics, **opt_metrics, **gmetrics}
-        return params, opt_state, out
-
-    return train_step
-
-
 # --------------------------------------------------------------------------
 # Sharded step: gather/slice by PartitionSpec + wire-format DP reduction
 # --------------------------------------------------------------------------
