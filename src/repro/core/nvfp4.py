@@ -118,6 +118,18 @@ def nvfp4_qdq(
 ) -> jax.Array:
     """Quantize ``x`` to NVFP4 along ``axis`` and dequantize back (simulation).
 
+    Two passes over ``x`` and no full-size intermediate: one reduction reads
+    ``x`` for the per-block amax (1/16 of its size; the tensor amax is their
+    max), and one elementwise pass reads ``x`` with the block scales,
+    broadcast inside the fusion, and writes the result in ``x``'s dtype.
+    Blocks are split in place, ``(.., n/16, 16, ..)`` at ``axis``, with no
+    ``moveaxis``: for a leading or middle axis the lane dimension is untouched;
+    for the last axis XLA:TPU still relayouts the input and the result once
+    each, in ``x``'s dtype (it cannot split the lane dimension in place). The
+    SR uniforms are drawn over the blocked shape with ``axis`` moved last,
+    ``(.., n/16, 16)``, and moved back: each element keeps the bits of the
+    moved-axis draw, which ``bench/reference.py`` reproduces.
+
     Args:
       x: input array (any float dtype; computation in fp32).
       axis: the GeMM contraction dimension — blocks of ``block_size`` run
@@ -141,43 +153,51 @@ def nvfp4_qdq(
     if sr and key is None:
         raise ValueError("stochastic rounding requires a PRNG key")
     orig_dtype = x.dtype
-    xf = x.astype(compute_dtype)
-    xf = jnp.moveaxis(xf, axis, -1)
-    moved_shape = xf.shape
-    n = moved_shape[-1]
+    axis = axis % x.ndim
+    n = x.shape[axis]
     pad = (-n) % block_size
     if pad:
-        xf = jnp.pad(xf, [(0, 0)] * (xf.ndim - 1) + [(0, pad)])
-    xb = xf.reshape(xf.shape[:-1] + (-1, block_size))
+        x = jnp.pad(x, [(0, pad if d == axis else 0) for d in range(x.ndim)])
+    padded = x.shape
+    nb = padded[axis] // block_size
+    # Behind a barrier, XLA cannot hoist this reshape (or the one at the end)
+    # through the elementwise chain, which would write the scales out at
+    # full size. The barriers change no value.
+    xb = jax.lax.optimization_barrier(
+        x.reshape(padded[:axis] + (nb, block_size) + padded[axis + 1:]))
+    xf = xb.astype(compute_dtype)
+    absx = jnp.abs(xf)
 
-    absx = jnp.abs(xb)
+    # pass 1: block amax, and the tensor amax from it
+    block_amax = jnp.max(absx, axis=axis + 1, keepdims=True)
     if tensor_amax is None:
-        tensor_amax = jnp.max(absx)
+        tensor_amax = jnp.max(block_amax)
     s_t = jnp.maximum(tensor_amax.astype(jnp.float32) / TENSOR_SCALE_DENOM, _EPS)
-
-    block_amax = jnp.max(absx, axis=-1, keepdims=True)
     s_b = quantize_block_scales(block_amax.astype(jnp.float32), s_t).astype(
         jnp.float32)
     scale = (s_b * s_t).astype(compute_dtype)  # effective per-block scale
 
-    eps = jnp.asarray(_EPS if compute_dtype == jnp.float32 else 1e-30,
-                      jnp.float32).astype(compute_dtype)
+    # pass 2: elementwise, the block scale broadcast in place
+    eps = jnp.asarray(_EPS, jnp.float32).astype(compute_dtype)
     a = jnp.where(scale > 0, absx / jnp.maximum(scale, eps), 0)
     if sr:
         # u in the compute dtype: bf16 quantizes P(up) to ~1/256 steps — an
         # SR bias bounded by 0.4% of one grid step, negligible vs FP4 noise.
-        u = jax.random.uniform(key, xb.shape, dtype=jnp.float32).astype(
-            compute_dtype
-        )
-        q = round_e2m1_sr(a, u)
+        moved = padded[:axis] + padded[axis + 1:] + (nb, block_size)
+        u = jax.random.uniform(key, moved, dtype=jnp.float32).astype(
+            compute_dtype)
+        q = round_e2m1_sr(a, jnp.moveaxis(u, (-2, -1), (axis, axis + 1)))
     else:
         q = round_e2m1_rn(a)
-    deq = jnp.sign(xb) * q * scale
-
-    deq = deq.reshape(moved_shape[:-1] + (n + pad,))
+    deq = jnp.sign(xf) * q * scale
+    if jnp.dtype(compute_dtype).itemsize >= jnp.dtype(orig_dtype).itemsize:
+        deq = deq.astype(orig_dtype)
+    # else cast after the barrier: next to the chain, XLA may keep it in the
+    # wider dtype and skip the compute dtype's rounding
+    deq = jax.lax.optimization_barrier(deq).reshape(padded)
     if pad:
-        deq = deq[..., :n]
-    return jnp.moveaxis(deq, -1, axis).astype(orig_dtype)
+        deq = jax.lax.slice_in_dim(deq, 0, n, axis=axis)
+    return deq.astype(orig_dtype)
 
 
 def nvfp4_quant_error(x: jax.Array, axis: int = -1, **kw) -> jax.Array:

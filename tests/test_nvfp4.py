@@ -5,10 +5,11 @@ import jax.numpy as jnp
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.core.formats import BLOCK_SIZE, E2M1_MAX
+from repro.core.formats import BLOCK_SIZE, E2M1_MAX, TENSOR_SCALE_DENOM
 from repro.core.nvfp4 import (
     nvfp4_qdq,
     nvfp4_quant_error,
+    quantize_block_scales,
     round_e2m1_rn,
     round_e2m1_sr,
 )
@@ -140,3 +141,80 @@ def test_error_metric_sane():
     x = jnp.asarray(rng.normal(size=(128, 128)).astype(np.float32))
     e = float(nvfp4_quant_error(x))
     assert 0.02 < e < 0.25
+
+
+def _moved_axis_qdq(x, axis=-1, *, sr=False, key=None, block_size=BLOCK_SIZE,
+                    tensor_amax=None, compute_dtype=jnp.float32):
+    """Frozen copy of the earlier ``nvfp4_qdq``: block axis moved last and
+    split into a 16-wide minor dimension, full-size ``|x|``. The two-pass
+    rewrite must equal it bit for bit."""
+    orig_dtype = x.dtype
+    xf = x.astype(compute_dtype)
+    xf = jnp.moveaxis(xf, axis, -1)
+    moved_shape = xf.shape
+    n = moved_shape[-1]
+    pad = (-n) % block_size
+    if pad:
+        xf = jnp.pad(xf, [(0, 0)] * (xf.ndim - 1) + [(0, pad)])
+    xb = xf.reshape(xf.shape[:-1] + (-1, block_size))
+    absx = jnp.abs(xb)
+    if tensor_amax is None:
+        tensor_amax = jnp.max(absx)
+    s_t = jnp.maximum(tensor_amax.astype(jnp.float32) / TENSOR_SCALE_DENOM,
+                      1e-30)
+    block_amax = jnp.max(absx, axis=-1, keepdims=True)
+    s_b = quantize_block_scales(block_amax.astype(jnp.float32), s_t).astype(
+        jnp.float32)
+    scale = (s_b * s_t).astype(compute_dtype)
+    eps = jnp.asarray(1e-30, jnp.float32).astype(compute_dtype)
+    a = jnp.where(scale > 0, absx / jnp.maximum(scale, eps), 0)
+    if sr:
+        u = jax.random.uniform(key, xb.shape, dtype=jnp.float32).astype(
+            compute_dtype)
+        q = round_e2m1_sr(a, u)
+    else:
+        q = round_e2m1_rn(a)
+    deq = jnp.sign(xb) * q * scale
+    deq = deq.reshape(moved_shape[:-1] + (n + pad,))
+    if pad:
+        deq = deq[..., :n]
+    return jnp.moveaxis(deq, -1, axis).astype(orig_dtype)
+
+
+_BF16, _F32 = jnp.bfloat16, jnp.float32
+# (shape, axis, sr, dtype, tensor_amax, compute_dtype, vmap over axis 0)
+_PIN_CASES = [
+    *[((64, 96), ax, sr, dt, None, _F32, False)
+      for ax in (0, 1, -1) for sr in (False, True) for dt in (_BF16, _F32)],
+    *[((40, 56), ax, sr, _BF16, None, _F32, False)          # ragged: 40, 56
+      for ax in (0, -1) for sr in (False, True)],
+    *[((3, 48, 40), ax, sr, _BF16, None, _F32, False)       # rank 3
+      for ax in (0, 1, -1) for sr in (False, True)],
+    *[((3, 48, 40), ax, False, dt, None, _F32, True)        # vmapped layers
+      for ax in (0, -1) for dt in (_BF16, _F32)],
+    *[((64, 96), ax, sr, _BF16, 7.5, _F32, False)           # given amax
+      for ax in (0, -1) for sr in (False, True)],
+    *[((40, 56), ax, sr, dt, None, _BF16, False)            # bf16 chain
+      for ax in (0, -1) for sr in (False, True) for dt in (_BF16, _F32)],
+    ((48, 40), -1, True, _F32, 3.0, _BF16, False),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,axis,sr,dtype,amax,compute,vmapped", _PIN_CASES,
+    ids=lambda v: jnp.dtype(v).name if isinstance(v, type) else str(v))
+def test_two_pass_bitwise_equals_moved_axis_form(shape, axis, sr, dtype, amax,
+                                                 compute, vmapped):
+    x = (jax.random.normal(jax.random.key(11), shape) * 3).astype(dtype)
+    kw = dict(sr=sr, key=jax.random.key(5) if sr else None,
+              compute_dtype=compute)
+    if amax is not None:
+        kw["tensor_amax"] = jnp.float32(amax)
+
+    def run(fn):
+        f = lambda a: fn(a, axis, **kw)
+        return np.asarray(jax.jit(jax.vmap(f) if vmapped else f)(x))
+
+    got, want = run(nvfp4_qdq), run(_moved_axis_qdq)
+    assert got.dtype == want.dtype and got.shape == want.shape == shape
+    np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))

@@ -10,13 +10,16 @@ The topology is described only inside the module fixture (never while a
 module is imported): only one process at a time may load the TPU library,
 so under several test workers only the worker running this file loads it.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.nvfp4 import nvfp4_qdq
 from repro.kernels import wire_fold
 from repro.kernels.fused import (center_hadamard_pack_2d,
                                  center_hadamard_qdq_2d, fused_amax_2d)
@@ -148,3 +151,49 @@ def test_kernel_compiles_for_v5e(chip, name):
     args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+# The stage path's QDQ (plain XLA, no kernel) at the shapes of one averis
+# GeMM: fwd activation, dw activation, dx and dw gradient (SR), fwd weight.
+QDQ_CASES = {
+    "fwd_act": ((TOKENS, D_MODEL), jnp.bfloat16, -1, False),
+    "dw_act": ((TOKENS, D_MODEL), jnp.bfloat16, 0, False),
+    "dx_grad_sr": ((TOKENS, D_FF), jnp.bfloat16, -1, True),
+    "dw_grad_sr": ((TOKENS, D_FF), jnp.bfloat16, 0, True),
+    "fwd_weight_f32": ((D_MODEL, D_FF), jnp.float32, 0, False),
+}
+# bytes accessed over the input's bytes: 1.25x the most the two-pass form
+# reads at these shapes (7.88x)
+QDQ_BYTES_BOUND = 9.85
+_ARRAY = re.compile(r"^\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\{([^}]*)\} "
+                    r"([\w-]+)\(")
+
+
+@pytest.mark.parametrize("name", sorted(QDQ_CASES))
+def test_nvfp4_qdq_two_passes_for_v5e(chip, name):
+    shape, dtype, axis, sr = QDQ_CASES[name]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)]
+    if sr:
+        args.append(jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                                         sharding=chip))
+    fn = lambda x, *k: nvfp4_qdq(x, axis, sr=sr, key=k[0] if sr else None)
+    compiled = jax.jit(fn).lower(*args).compile()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    in_bytes = math.prod(shape) * jnp.dtype(dtype).itemsize
+    assert cost["bytes accessed"] <= QDQ_BYTES_BOUND * in_bytes, name
+
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    full = [m.groups() for m in map(_ARRAY.match, entry.splitlines())
+            if m and m.group(2) and math.prod(
+                int(d) for d in m.group(2).split(",")) == math.prod(shape)]
+    # no full-size float32 |x| or broadcast scale: every full-size array is
+    # the input, the output, or a view or copy of them in the input's dtype
+    hlo_dtype = jnp.dtype(dtype).name.replace("float", "f")
+    assert {dt for dt, *_ in full} == {hlo_dtype}, (name, full)
+    assert not [op for *_, op in full if op == "broadcast"], name
+    copies = [lay for _, _, lay, op in full if op == "copy"]
+    # blocks along a leading axis are split in place; along the lane axis
+    # XLA:TPU relayouts the input once and the output once, at most
+    assert len(copies) <= (0 if axis == 0 else 2), (name, copies)
